@@ -79,6 +79,7 @@ import time
 from typing import Any, Optional, Sequence, Union
 
 import numpy as np
+from jax.profiler import annotate_function
 
 from . import batch, golden, machine
 from .batch import PackedPopulation
@@ -88,6 +89,7 @@ from .costs import (ALL_SCHEDULERS, FUNC_NAMES, NUM_FUNCS, SchedulerCosts,
 from .frontend import StreamSet
 from .golden import HtsParams
 from .policy import SchedPolicy
+from .spans import Spans
 
 
 class SimulationError(RuntimeError):
@@ -575,6 +577,7 @@ class PopulationResult:
         return "\n".join(lines)
 
 
+@functools.partial(annotate_function, name="hts.run_many")
 def run_many(programs, *,
              scheduler: Union[str, SchedulerCosts] = "hts_spec",
              n_fu: Union[int, Sequence] = 2, backend: str = "jax",
@@ -621,22 +624,28 @@ def run_many(programs, *,
     import jax
     import jax.numpy as jnp
 
-    pop = (programs if isinstance(programs, PackedPopulation)
-           else batch.pack_population(programs, params=params, n_fu=n_fu,
-                                      policy=policy, fu_cost=fu_cost,
-                                      max_prog=max_prog))
+    spans = Spans()
+    if isinstance(programs, PackedPopulation):
+        pop = programs
+    else:
+        with spans("hts.pack"):
+            pop = batch.pack_population(programs, params=params, n_fu=n_fu,
+                                        policy=policy, fu_cost=fu_cost,
+                                        max_prog=max_prog)
     cost = _norm_costs(scheduler)
 
     if devices is not None and backend != "jax":
         raise ValueError(f'devices= requires backend="jax", got {backend!r}')
     if backend == "golden":
-        t0 = time.perf_counter()
-        results = tuple(
-            run(prep, scheduler=cost, n_fu=tuple(int(x) for x in pop.n_fu[i]),
-                backend="golden", params=pop.params, max_cycles=max_cycles,
-                policy=pop.policies[i], fu_cost=pop.fu_cost[i], check=check)
-            for i, prep in enumerate(pop.preps))
-        wall = (time.perf_counter() - t0) * 1e6
+        with spans("hts.launch"):
+            results = tuple(
+                run(prep, scheduler=cost,
+                    n_fu=tuple(int(x) for x in pop.n_fu[i]),
+                    backend="golden", params=pop.params,
+                    max_cycles=max_cycles, policy=pop.policies[i],
+                    fu_cost=pop.fu_cost[i], check=check)
+                for i, prep in enumerate(pop.preps))
+        wall = spans.seconds("hts.launch") * 1e6
         return PopulationResult(
             scheduler=cost.name, backend="golden", names=pop.names,
             n_fu=pop.n_fu, cycles=np.asarray([r.cycles for r in results]),
@@ -666,10 +675,10 @@ def run_many(programs, *,
         run_pop = shard.pad_lanes(pop, devices)
     else:
         run_pop = pop
-    t0 = time.perf_counter()
-    out = runner(*(jnp.asarray(a) for a in run_pop.machine_args()))
-    out = jax.tree.map(np.asarray, out)      # forces device completion
-    wall = (time.perf_counter() - t0) * 1e6
+    with spans("hts.launch"):
+        out = runner(*(jnp.asarray(a) for a in run_pop.machine_args()))
+        out = jax.tree.map(np.asarray, out)  # forces device completion
+    wall = spans.seconds("hts.launch") * 1e6
     if len(run_pop) > len(pop):              # drop the shard-padding lanes
         out = {k: v[:len(pop)] for k, v in out.items()}
 
@@ -815,6 +824,7 @@ def _slicer_for(spec: machine.MachineSpec, max_prog: int,
     return shard.sharded_slicer(spec, max_prog, devices)
 
 
+@functools.partial(annotate_function, name="hts.sweep")
 def sweep(program, *, n_fu=(1, 2, 4), schedulers=("naive", "hts_spec"),
           params: HtsParams = HtsParams(), event_skip: bool = True,
           max_cycles: int = 50_000_000, max_prog: Optional[int] = None,
@@ -899,10 +909,11 @@ def sweep(program, *, n_fu=(1, 2, 4), schedulers=("naive", "hts_spec"),
                                    max_cycles=max_cycles,
                                    max_fu_per_class=max_fu_per_class)
         runner = _vmapped(spec, run_prog, axes)
-        t0 = time.perf_counter()
-        out = runner(*args)
-        cyc = np.asarray(out["cycles"])
-        wall[cost.name] = (time.perf_counter() - t0) * 1e6
+        spans = Spans()
+        with spans("hts.launch", scheduler=cost.name):
+            out = runner(*args)
+            cyc = np.asarray(out["cycles"])
+        wall[cost.name] = spans.seconds("hts.launch") * 1e6
         ok = np.asarray(out["halted"]) & ~np.asarray(out["overflow"])
         if not ok.all():
             bad = [point_names[i] for i in np.nonzero(~ok.ravel())[0]]

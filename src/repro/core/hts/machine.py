@@ -44,6 +44,19 @@ NEG = np.int32(-1)
 BIG = np.int32(2**30)
 
 
+def _named(name: str):
+    """Decorator: the function's operations carry ``jax.named_scope(name)``
+    in their metadata (``op_name``), so a profile names the step phase an
+    operation belongs to.  Metadata only: the computation is unchanged."""
+    def wrap(fn):
+        @functools.wraps(fn)
+        def scoped(*args, **kwargs):
+            with jax.named_scope(name):
+                return fn(*args, **kwargs)
+        return scoped
+    return wrap
+
+
 @dataclasses.dataclass(frozen=True)
 class ResumableMachine:
     """The population machine factored into snapshot/resume pieces.
@@ -210,6 +223,7 @@ def make_machine(spec: MachineSpec, max_prog: int = 256,
     c_iota = jnp.arange(C, dtype=I32)
     u_iota = jnp.arange(U, dtype=I32)
 
+    @_named("trace_write")
     def trace_write(arr, uid, value, enable):
         """``arr[uid] = value where enable`` for uid-indexed trace arrays.
 
@@ -236,6 +250,7 @@ def make_machine(spec: MachineSpec, max_prog: int = 256,
             hit = (enable[:, None] & (uid[:, None] == u_iota[None, :])).any(0)
         return jnp.where(hit, value, arr)
 
+    @_named("trace_write")
     def tw_scatter(arr, uid, value, enable):
         """The single-machine scatter form of :func:`trace_write`, used
         *inside* pallas kernels too: a kernel body runs per lane (no batch
@@ -250,11 +265,13 @@ def make_machine(spec: MachineSpec, max_prog: int = 256,
     # recomputing it per array.  On the scatter path the selector is the
     # guarded index itself; the base population machine shares the
     # (U,)-wide one-hot (the dominant per-lane cost of its trace writes).
+    @_named("trace_write")
     def trace_sel(uid, enable):
         if not population or not base:
             return jnp.where(enable, uid, U)
         return enable & (u_iota == uid)
 
+    @_named("trace_write")
     def trace_put(arr, sel, value):
         if not population or not base:
             return arr.at[sel].set(value, mode="drop")
@@ -358,6 +375,7 @@ def make_machine(spec: MachineSpec, max_prog: int = 256,
                                             jnp.where(mask, vals, cur),
                                             (dst_c,))
 
+    @_named("fu_exec")
     def fu_exec(st, exists, effect, alive):
         """Per-unit execution tick + completion memory writes; returns the
         ``done`` mask for the slot-side CDB enqueue (its own phase so the
@@ -377,6 +395,7 @@ def make_machine(spec: MachineSpec, max_prog: int = 256,
         st["mem"] = jax.lax.fori_loop(0, NFU, mem_trip, st["mem"])
         return st, done
 
+    @_named("cdb_enqueue")
     def cdb_enqueue(st, done, tw):
         # --- CDB enqueue: k-th done unit → k-th free slot, ticket + k.
         # Written slot-side ((C,)-wide selects + gathers, no scatters —
@@ -423,6 +442,7 @@ def make_machine(spec: MachineSpec, max_prog: int = 256,
     # ------------------------------------------------------------------
     # phase 2+3: memread tick and CDB grant
     # ------------------------------------------------------------------
+    @_named("memread_tick")
     def memread_tick(st, alive):
         ticking = st["mr_active"] & alive
         rem = jnp.where(ticking, st["mr_rem"] - st["dt"], st["mr_rem"])
@@ -431,6 +451,7 @@ def make_machine(spec: MachineSpec, max_prog: int = 256,
         st["mr_active"] = st["mr_active"] & ~fired
         return st, fired
 
+    @_named("cdb_grant")
     def cdb_grant(st, br_ready, alive, tw, unroll=False):
         def grant_one(st, br_ready):
             ready = st["cdb_valid"] & (st["cdb_ready"] <= st["cycle"]) & alive
@@ -464,6 +485,7 @@ def make_machine(spec: MachineSpec, max_prog: int = 256,
     # ------------------------------------------------------------------
     # phase 4: branch resolution
     # ------------------------------------------------------------------
+    @_named("branch_core")
     def branch_core(st, br_ready):
         """Branch resolution minus the two ``tr_aborted`` trace writes —
         returns the kill masks plus the uid arrays *as of the squash* (the
@@ -510,6 +532,7 @@ def make_machine(spec: MachineSpec, max_prog: int = 256,
         st["br_active"] = st["br_active"] & ~fire
         return st, (rs_uid_k, rs_kill, fu_uid_k, fu_kill)
 
+    @_named("abort_traces")
     def abort_traces(st, kills, tw):
         rs_uid_k, rs_kill, fu_uid_k, fu_kill = kills
         st["tr_aborted"] = tw(st["tr_aborted"], rs_uid_k, True, rs_kill)
@@ -660,7 +683,7 @@ def make_machine(spec: MachineSpec, max_prog: int = 256,
              & (f_rank[:, None] == unit_rank[None, :]))
         return _issue_apply(st, m, fire, cost, tw)
 
-    rs_issue = rs_issue_base if base else rs_issue_fast
+    rs_issue = _named("rs_issue")(rs_issue_base if base else rs_issue_fast)
 
     # ------------------------------------------------------------------
     # phase 6: frontend — N per-tenant streams, one arbitrated dispatch.
@@ -674,6 +697,7 @@ def make_machine(spec: MachineSpec, max_prog: int = 256,
     # model's head-of-line stall.  A single stream covering [0, p_len)
     # reduces to the historical merged frontend bit-for-bit.
     # ------------------------------------------------------------------
+    @_named("frontend_core")
     def frontend_core(st, F, p_len, rs_cap, streams, alive):
         NS = streams.shape[0]
         ns_iota = jnp.arange(NS, dtype=I32)
@@ -914,6 +938,7 @@ def make_machine(spec: MachineSpec, max_prog: int = 256,
         fe = dict(uid=uidc, acc=acc, dep=dep, pid=pidv, dispatch=dispatch)
         return st, fe
 
+    @_named("dispatch_traces")
     def dispatch_traces(st, fe, tw=None):
         if tw is not None or base:
             tw = tw or trace_write
@@ -936,6 +961,7 @@ def make_machine(spec: MachineSpec, max_prog: int = 256,
     # ------------------------------------------------------------------
     # event-skip: time to the next scheduler event
     # ------------------------------------------------------------------
+    @_named("next_dt")
     def next_dt(st, exists, F, p_len, rs_cap, streams):
         if not spec.event_skip:
             return I32(1)
@@ -997,6 +1023,7 @@ def make_machine(spec: MachineSpec, max_prog: int = 256,
         return (~st["halted"] & ~st["overflow"]
                 & (st["cycle"] < spec.max_cycles))
 
+    @_named("step_top")
     def step_top(st, streams, limit):
         # ``alive`` gates every phase: a halted/overflowed lane is a fixed
         # point of the step, so the batched population machine can run one
@@ -1029,6 +1056,7 @@ def make_machine(spec: MachineSpec, max_prog: int = 256,
             alive & w_stalled, st["dt"] - 1, 0)
         return st, alive
 
+    @_named("step_bottom")
     def step_bottom(st, exists, F, p_len, rs_cap, streams, alive):
         done = ((st["pc"] >= streams[:, 1]).all() & ~st["rs_valid"].any()
                 & ~st["fu_busy"].any()
@@ -1109,7 +1137,8 @@ def make_machine(spec: MachineSpec, max_prog: int = 256,
             ins = {k: st[k] for k in ENQ_KEYS}
             ins.update(done=fu_done, cycle=st["cycle"],
                        fu_spec=st["fu_spec"])
-            st.update(ps.lane_phase(k_enqueue, ins, ENQ_KEYS))
+            with jax.named_scope("cdb_enqueue"):
+                st.update(ps.lane_phase(k_enqueue, ins, ENQ_KEYS))
 
             st, fired = jax.vmap(memread_tick)(st, alive)
 
@@ -1119,7 +1148,9 @@ def make_machine(spec: MachineSpec, max_prog: int = 256,
                        trk_uid=st["trk_uid"], br_active=st["br_active"],
                        br_kind=st["br_kind"], br_wait=st["br_wait"],
                        br_ready=fired, alive=alive)
-            out = ps.lane_phase(k_grant, ins, GRANT_KEYS + ("br_ready",))
+            with jax.named_scope("cdb_grant"):
+                out = ps.lane_phase(k_grant, ins,
+                                    GRANT_KEYS + ("br_ready",))
             br_ready = out.pop("br_ready")
             st.update(out)
 
@@ -1134,7 +1165,8 @@ def make_machine(spec: MachineSpec, max_prog: int = 256,
                        rs_src=st["rs_src"], rs_spec=st["rs_spec"],
                        cycle=st["cycle"], exists=exists, prio=prio,
                        quota=quota, cost=cost, eft=eft, alive=alive)
-            st.update(ps.lane_phase(k_issue, ins, ISSUE_KEYS))
+            with jax.named_scope("rs_issue"):
+                st.update(ps.lane_phase(k_issue, ins, ISSUE_KEYS))
 
             st, fe = jax.vmap(frontend_core)(st, F, p_len, rs_cap, streams,
                                              alive)
@@ -1145,7 +1177,9 @@ def make_machine(spec: MachineSpec, max_prog: int = 256,
                        uid=fe["uid"], acc=fe["acc"], dep=fe["dep"],
                        pid=fe["pid"], dispatch=fe["dispatch"],
                        cycle=st["cycle"])
-            st.update(ps.lane_phase(k_traces, ins, TRACE_KEYS))
+            # one kernel writes the abort and the dispatch traces
+            with jax.named_scope("dispatch_traces"):
+                st.update(ps.lane_phase(k_traces, ins, TRACE_KEYS))
 
             return jax.vmap(step_bottom)(st, exists, F, p_len, rs_cap,
                                          streams, alive)

@@ -63,10 +63,13 @@ The pieces, and why each exists:
   sharded resumable machine), so a multi-device host drains each batch
   across its devices; lane refill composes (the lane width is pinned to a
   device multiple once per server).
-* **Service metrics** — every completed request records its queue wait
-  and time-to-result; :meth:`Server.report` aggregates per bucket and per
-  tenant (measured slice occupancy included), feeding
-  ``benchmarks/serving.py``.
+* **Service metrics** — every request records when it was submitted,
+  put in a lane and resolved, and every launch why it started and how
+  long it spent in its host phases (``hts.serve.*`` spans, which a
+  ``jax.profiler`` trace also shows); :meth:`Server.report` gives
+  per-bucket and per-tenant means, the tails of queue wait and time in a
+  lane, and launch delay, wall and host share, over everything or from a
+  given time on.
 
     >>> from repro.core import hts
     >>> with hts.serve(max_batch=4, deadline=0.01) as srv:
@@ -101,6 +104,7 @@ from . import api, batch, machine
 from .costs import SchedulerCosts
 from .golden import HtsParams
 from .policy import SchedPolicy
+from .spans import Spans
 
 
 class QueueFullError(RuntimeError):
@@ -216,40 +220,105 @@ class BucketStats:
     occupancy: float            # mean real-lane fraction (measured per
     #                             slice for compacted launches)
     mean_wait: float            # seconds queued before a lane ran it
-    mean_ttr: float             # seconds submit -> result
 
 
 @dataclasses.dataclass(frozen=True)
 class TenantStats:
     requests: int
     mean_wait: float
-    mean_ttr: float
+
+
+#: why a launch started: its oldest request aged past ``deadline``
+#: (``poll``), a flush (``drain``/``close``), a full queue under
+#: slice-and-refill, or a full batch in static mode (both in ``submit``)
+LAUNCH_REASONS = ("deadline", "drain", "pressure", "full")
+#: the host phases of a launch, each a ``hts.serve.<phase>`` span
+LAUNCH_PHASES = ("pack", "slice", "harvest", "refill")
 
 
 @dataclasses.dataclass(frozen=True)
 class ServeReport:
-    """Aggregated service metrics for everything the server completed."""
+    """Service metrics over the requests submitted and the launches
+    started in the report's span of time (all of them by default).
+
+    Times are seconds on the server's clock.  A request is submitted
+    when it is enqueued; ``submit_hold_*`` is how long ``submit()`` held
+    the caller before that (the deadline flush it runs first may
+    launch).  ``queue_wait_*`` runs from submit to the request's first
+    slice (as ``BucketStats.mean_wait``), ``in_lane_*`` from there to its
+    result, and ``slices_*`` counts the slices it spent in a lane.
+    ``launch_delay_*`` runs from a launch's oldest request's submit to the
+    launch's start; ``launch_wall_*`` is how long a launch held the
+    caller's thread, of which ``host_share`` (over all launches) was not
+    spent in ``slice`` (dispatch and the wait on the device).
+    ``launch_queue_*`` counts the requests pending server-wide when a
+    launch started, ``launch_slices_*`` the slices it ran.  ``phase_s``
+    totals each of :data:`LAUNCH_PHASES`.  A statistic with nothing to
+    describe is NaN."""
     requests: int
     batches: int
     per_bucket: dict
     per_tenant: dict
+    submit_hold_mean: float
+    submit_hold_max: float
+    queue_wait_p50: float
+    queue_wait_p95: float
+    in_lane_mean: float
+    in_lane_p50: float
+    in_lane_p95: float
+    slices_p50: float
+    slices_p95: float
+    launch_delay_mean: float
+    launch_delay_max: float
+    launch_wall_mean: float
+    launch_wall_max: float
+    launch_queue_mean: float
+    launch_queue_max: float
+    launch_slices_mean: float
+    launch_slices_max: float
+    host_share: float
+    phase_s: dict
+    launches_by_reason: dict
 
     def table(self) -> str:
         lines = [f"served {self.requests} requests in {self.batches} "
                  f"batches",
                  f"{'bucket':<14} {'batches':>7} {'reqs':>6} {'occ':>6} "
-                 f"{'wait(ms)':>9} {'ttr(ms)':>9}"]
+                 f"{'wait(ms)':>9}"]
         for key, b in sorted(self.per_bucket.items()):
             lines.append(f"{str(key):<14} {b.batches:>7} {b.requests:>6} "
-                         f"{b.occupancy:>6.2f} {b.mean_wait * 1e3:>9.3f} "
-                         f"{b.mean_ttr * 1e3:>9.3f}")
+                         f"{b.occupancy:>6.2f} {b.mean_wait * 1e3:>9.3f}")
         if self.per_tenant:
             lines.append(f"{'tenant':<14} {'':>7} {'reqs':>6} {'':>6} "
-                         f"{'wait(ms)':>9} {'ttr(ms)':>9}")
+                         f"{'wait(ms)':>9}")
             for name, t in sorted(self.per_tenant.items()):
                 lines.append(f"{name:<14} {'':>7} {t.requests:>6} {'':>6} "
-                             f"{t.mean_wait * 1e3:>9.3f} "
-                             f"{t.mean_ttr * 1e3:>9.3f}")
+                             f"{t.mean_wait * 1e3:>9.3f}")
+
+        for label, a, b in (
+                ("submit hold mean/max", self.submit_hold_mean,
+                 self.submit_hold_max),
+                ("queue wait p50/p95", self.queue_wait_p50,
+                 self.queue_wait_p95),
+                ("in lane p50/p95", self.in_lane_p50, self.in_lane_p95),
+                ("launch delay mean/max", self.launch_delay_mean,
+                 self.launch_delay_max),
+                ("launch wall mean/max", self.launch_wall_mean,
+                 self.launch_wall_max)):
+            lines.append(f"{label + ' (ms)':<27} {a * 1e3:>9.3f} "
+                         f"{b * 1e3:>9.3f}")
+        for label, a, b in (
+                ("slices a request p50/p95", self.slices_p50,
+                 self.slices_p95),
+                ("queue at launch mean/max", self.launch_queue_mean,
+                 self.launch_queue_max),
+                ("slices a launch mean/max", self.launch_slices_mean,
+                 self.launch_slices_max)):
+            lines.append(f"{label:<27} {a:>9.1f} {b:>9.1f}")
+        lines.append(f"{'host share of launch wall':<27} "
+                     f"{self.host_share:>9.3f}")
+        lines.append(f"{'launches by reason':<27} " + " ".join(
+            f"{k}={n}" for k, n in self.launches_by_reason.items()))
         return "\n".join(lines)
 
 
@@ -283,7 +352,8 @@ def _tree_ops():
 class _Request:
     prep: batch.Prepared
     tenant: str
-    t_submit: float
+    t_enter: float              # submit() was called
+    t_submit: float             # enqueued, after submit()'s deadline flush
     future: Future
 
 
@@ -291,6 +361,45 @@ class _Request:
 class _OpenBatch:
     t_open: float
     requests: list
+
+
+@dataclasses.dataclass(frozen=True)
+class _RequestRow:
+    """One resolved request, on the server's clock."""
+    bucket: tuple
+    tenant: str
+    enter: float                # submit() was called
+    submit: float               # enqueued
+    fill: float                 # its first slice started
+    done: float                 # its future resolved
+    slices: int                 # slices it spent in a lane
+
+
+@dataclasses.dataclass(frozen=True)
+class _LaunchRow:
+    """One finished launch, on the server's clock."""
+    bucket: tuple
+    start: float
+    end: float
+    reason: str                 # one of LAUNCH_REASONS
+    queue: int                  # requests pending server-wide at start
+    oldest: float               # submit time of its oldest request
+    pad: int
+    occupancy: float
+    slices: int
+    phase_s: dict               # LAUNCH_PHASES -> seconds
+
+
+def _pct(xs, q: float) -> float:
+    return float(np.percentile(xs, q)) if len(xs) else float("nan")
+
+
+def _mean(xs) -> float:
+    return float(np.mean(xs)) if len(xs) else float("nan")
+
+
+def _max(xs) -> float:
+    return float(np.max(xs)) if len(xs) else float("nan")
 
 
 class Server:
@@ -327,8 +436,8 @@ class Server:
         self._misses = 0
         self._pending = 0
         self._closed = False
-        self._req_rows: list = []      # (bucket, tenant, wait, ttr)
-        self._batch_rows: list = []    # (bucket, n_real, pad, occupancy)
+        self._req_rows: list = []      # _RequestRow per resolved request
+        self._batch_rows: list = []    # _LaunchRow per finished launch
         self._done_steps: dict[tuple[int, int], list] = {}
 
     # -- admission ----------------------------------------------------------
@@ -362,6 +471,7 @@ class Server:
         Open-loop producers must shed or retry on refusal.
         """
         self._require_open()
+        t_enter = self._clock.now()
         self.poll()                     # free space deadlines already owe
         prep = batch.prepare(program)
         key = self._bucket_key(prep)
@@ -372,7 +482,7 @@ class Server:
             raise QueueFullError(
                 f"{self._pending} requests pending >= max_queue "
                 f"{self.spec.max_queue}")
-        req = _Request(prep=prep, tenant=tenant,
+        req = _Request(prep=prep, tenant=tenant, t_enter=t_enter,
                        t_submit=self._clock.now(), future=Future())
         if ob is None:
             ob = self._open[key] = _OpenBatch(t_open=req.t_submit,
@@ -384,7 +494,7 @@ class Server:
         # launches on deadline/drain — or right here under queue pressure
         if len(ob.requests) >= self.spec.max_batch and (
                 full or not self._compaction):
-            self._launch(key)
+            self._launch(key, "pressure" if self._compaction else "full")
         return req.future
 
     def poll(self) -> int:
@@ -395,7 +505,7 @@ class Server:
         due = [k for k, ob in self._open.items()
                if now - ob.t_open >= self.spec.deadline]
         for k in due:
-            self._launch(k)
+            self._launch(k, "deadline")
         return len(due)
 
     def drain(self) -> int:
@@ -403,7 +513,7 @@ class Server:
         self._require_open()
         keys = list(self._open)
         for k in keys:
-            self._launch(k)
+            self._launch(k, "drain")
         return len(keys)
 
     def close(self) -> None:
@@ -474,13 +584,15 @@ class Server:
             policy=self.spec.policy, max_prog=key[0], max_streams=key[1])
 
     def _resolve(self, key, req: _Request, result, t_filled: float,
-                 t_done: float) -> None:
+                 slices: int) -> None:
         """Resolve one request's future (and its accounting) — the ONLY
         place ``_pending`` decrements, so a request admitted is a request
         either resolved or still counted."""
         self._pending -= 1
-        self._req_rows.append((key, req.tenant, t_filled - req.t_submit,
-                               t_done - req.t_submit))
+        self._req_rows.append(_RequestRow(
+            bucket=key, tenant=req.tenant, enter=req.t_enter,
+            submit=req.t_submit, fill=t_filled, done=self._clock.now(),
+            slices=slices))
         if result is not None:
             self._done_steps.setdefault(key, []).append(
                 int(np.asarray(result.raw["steps"])))
@@ -490,15 +602,18 @@ class Server:
                 f"request {req.prep.name!r} (tenant {req.tenant!r}) did "
                 f"not halt within {self.spec.max_cycles} cycles"))
 
-    def _launch(self, key: tuple[int, int]) -> None:
+    def _launch(self, key: tuple[int, int], reason: str) -> None:
         ob = self._open.pop(key, None)
         if ob is None or not ob.requests:
             return
+        spans = Spans(self._clock.now)
+        queue = self._pending
         try:
-            if self._compaction:
-                self._launch_sliced(key, ob.requests)
-            else:
-                self._launch_static(key, ob.requests)
+            with spans("hts.serve.launch", bucket=str(key), lanes=self._lanes,
+                       queue=queue, reason=reason):
+                run = (self._launch_sliced if self._compaction
+                       else self._launch_static)
+                pad, occupancy, slices = run(key, ob.requests, spans)
         except BaseException as e:
             # exception-safe: a failed launch fails its *own* futures and
             # restores the queue accounting, instead of leaking hung
@@ -508,27 +623,39 @@ class Server:
                     self._pending -= 1
                     r.future.set_exception(e)
             raise
+        _, start, end = spans.rows[-1]       # the launch span closes last
+        self._batch_rows.append(_LaunchRow(
+            bucket=key, start=start, end=end, reason=reason, queue=queue,
+            oldest=ob.requests[0].t_submit, pad=pad, occupancy=occupancy,
+            slices=slices,
+            phase_s={p: spans.seconds(f"hts.serve.{p}")
+                     for p in LAUNCH_PHASES}))
 
-    def _launch_static(self, key: tuple[int, int], reqs: list) -> None:
+    def _launch_static(self, key: tuple[int, int], reqs: list,
+                       spans: Spans) -> tuple:
+        """One run-to-completion launch; returns (pad lanes, occupancy,
+        slices) for the launch's row."""
         self._runner(key)           # cache accounting (run_many reuses it)
         # pad to the bucket's one-and-only launch shape (replicating the
         # first request — pad results are discarded)
         pad = self._lanes - len(reqs)
         preps = [r.prep for r in reqs] + [reqs[0].prep] * pad
-        pop = self._pack(preps, key)
+        with spans("hts.serve.pack"):
+            pop = self._pack(preps, key)
         t_launch = self._clock.now()
-        res = api.run_many(pop, scheduler=self._cost,
-                           event_skip=self.spec.event_skip,
-                           max_cycles=self.spec.max_cycles,
-                           max_fu_per_class=self._max_fu,
-                           devices=self.spec.devices, check=False,
-                           step_impl=self.spec.step_impl)
-        t_done = self._clock.now()
-        self._batch_rows.append((key, len(reqs), pad,
-                                 len(reqs) / self._lanes))
-        for i, r in enumerate(reqs):
-            self._resolve(key, r, res[i] if bool(res.halted[i]) else None,
-                          t_launch, t_done)
+        with spans("hts.serve.slice"):
+            res = api.run_many(pop, scheduler=self._cost,
+                               event_skip=self.spec.event_skip,
+                               max_cycles=self.spec.max_cycles,
+                               max_fu_per_class=self._max_fu,
+                               devices=self.spec.devices, check=False,
+                               step_impl=self.spec.step_impl)
+        with spans("hts.serve.harvest"):
+            for i, r in enumerate(reqs):
+                self._resolve(key, r,
+                              res[i] if bool(res.halted[i]) else None,
+                              t_launch, 1)
+        return pad, len(reqs) / self._lanes, 1
 
     # -- slice-and-refill (compaction) --------------------------------------
     def _slice_budget(self, key: tuple[int, int]) -> int:
@@ -566,12 +693,14 @@ class Server:
         crow["mem"] = row.mem[0]
         return crow, arow
 
-    def _launch_sliced(self, key: tuple[int, int], reqs: list) -> None:
+    def _launch_sliced(self, key: tuple[int, int], reqs: list,
+                       spans: Spans) -> tuple:
         """Run one bucket's queue through ``self._lanes`` lanes with
         bounded step slices, harvesting halted lanes and refilling their
         slots between slices, until the queue is dry and every lane has
         drained.  Each request's future resolves the moment its own lane
-        halts — not when the batch does.
+        halts — not when the batch does.  Returns (pad lanes, occupancy,
+        slices) for the launch's row.
 
         The carry and the 11 machine arguments stay **device-resident**
         across slices: per slice only the three per-lane liveness fields
@@ -589,67 +718,80 @@ class Server:
         W = self._lanes
         take, queue = queue[:W], queue[W:]
         pad = W - len(take)
-        pop = self._pack([r.prep for r in take] + [take[0].prep] * pad, key)
-        args = [jnp.asarray(a) for a in pop.machine_args()]
-        n_fu_host = np.array(pop.machine_args()[2])   # host mirror for reads
-        carry = dict(rm.init(*args))
-        # one fresh state row as the refill template: machine.init only
-        # varies pc and mem with the program (documented invariant), so a
-        # fresh row for ANY program is this template + those two fields
-        fresh = jax.device_get({k: v[0] for k, v in carry.items()})
+        with spans("hts.serve.pack"):
+            pop = self._pack([r.prep for r in take] + [take[0].prep] * pad,
+                             key)
+            args = [jnp.asarray(a) for a in pop.machine_args()]
+            n_fu_host = np.array(pop.machine_args()[2])  # host mirror
+            carry = dict(rm.init(*args))
+            # one fresh state row as the refill template: machine.init
+            # only varies pc and mem with the program (documented
+            # invariant), so a fresh row for ANY program is this
+            # template + those two fields
+            fresh = jax.device_get({k: v[0] for k, v in carry.items()})
+            # retire pad lanes before the first slice: marking the clones
+            # halted makes them step fixed points and immediately
+            # refillable
+            if pad:
+                carry["halted"] = carry["halted"].at[len(take):].set(True)
         lanes: list = list(take) + [None] * pad
-        # retire pad lanes before the first slice: marking the clones
-        # halted makes them step fixed points and immediately refillable
-        if pad:
-            carry["halted"] = carry["halted"].at[len(take):].set(True)
         t_fill = [self._clock.now()] * W
+        n_slices = [0] * W                       # slices run per lane
         served = 0
         occ_num = 0.0
         occ_den = 0
         while any(r is not None for r in lanes):
             occ_num += sum(r is not None for r in lanes) / W
             occ_den += 1
+            for i, r in enumerate(lanes):
+                n_slices[i] += r is not None
             budget = np.int32(self._slice_budget(key))
-            carry = dict(rm.run_slice(carry, *args, budget))
-            now = self._clock.now()
-            halted, overflow, cycle = jax.device_get(
-                (carry["halted"], carry["overflow"], carry["cycle"]))
+            with spans("hts.serve.slice"):
+                carry = dict(rm.run_slice(carry, *args, budget))
+                now = self._clock.now()
+                halted, overflow, cycle = jax.device_get(
+                    (carry["halted"], carry["overflow"], carry["cycle"]))
             dead = halted | overflow | (cycle >= self.spec.max_cycles)
             done = [i for i in range(W)
                     if lanes[i] is not None and dead[i]]
             if not done:
                 continue
-            # one gather for every lane that died this slice (index vector
-            # padded to W so the helper keeps a single compiled shape)
-            idx = np.asarray(done + [done[0]] * (W - len(done)), np.int32)
-            rows = jax.device_get(take_rows(carry, idx))
-            ref_idx: list[int] = []
-            ref_crows: list[dict] = []
-            ref_arows: list[list] = []
-            for j, i in enumerate(done):
-                r = lanes[i]
-                row = rm.collect({k: v[j] for k, v in rows.items()})
-                res = self._lane_result(r, row, n_fu_host[i],
-                                        (now - t_fill[i]) * 1e6)
-                self._resolve(key, r, res if res.halted else None,
-                              t_fill[i], now)
-                served += 1
-                lanes[i] = None
-                if queue:
+            with spans("hts.serve.harvest"):
+                # one gather for every lane that died this slice (index
+                # vector padded to W so the helper keeps a single compiled
+                # shape)
+                idx = np.asarray(done + [done[0]] * (W - len(done)),
+                                 np.int32)
+                rows = jax.device_get(take_rows(carry, idx))
+                for j, i in enumerate(done):
+                    r = lanes[i]
+                    row = rm.collect({k: v[j] for k, v in rows.items()})
+                    res = self._lane_result(r, row, n_fu_host[i],
+                                            (now - t_fill[i]) * 1e6)
+                    self._resolve(key, r, res if res.halted else None,
+                                  t_fill[i], n_slices[i])
+                    served += 1
+                    lanes[i] = None
+            refill = done[:len(queue)]
+            if not refill:
+                continue
+            with spans("hts.serve.refill"):
+                ref_crows: list[dict] = []
+                ref_arows: list[list] = []
+                for i in refill:
                     nxt = queue.pop(0)
                     crow, arow = self._refill_rows(key, fresh, nxt)
-                    ref_idx.append(i)
                     ref_crows.append(crow)
                     ref_arows.append(arow)
                     n_fu_host[i] = np.array(arow[2])
                     lanes[i] = nxt
                     t_fill[i] = now
-            if ref_idx:
+                    n_slices[i] = 0
                 # one splice for every refill this slice (padded with
                 # duplicates of refill 0 — identical rows, so the scatter
                 # is order-independent)
-                k = W - len(ref_idx)
-                ridx = np.asarray(ref_idx + [ref_idx[0]] * k, np.int32)
+                k = W - len(refill)
+                ridx = np.asarray(refill + [refill[0]] * k, np.int32)
                 ref_crows += [ref_crows[0]] * k
                 ref_arows += [ref_arows[0]] * k
                 crows = {f: np.stack([c[f] for c in ref_crows])
@@ -658,8 +800,7 @@ class Server:
                          for j in range(len(args))]
                 carry, args = put_rows((carry, args), ridx, (crows, arows))
                 carry, args = dict(carry), list(args)
-        self._batch_rows.append((key, served, max(0, W - served),
-                                 occ_num / max(occ_den, 1)))
+        return max(0, W - served), occ_num / max(occ_den, 1), occ_den
 
     # -- introspection ------------------------------------------------------
     def cache_info(self) -> CacheInfo:
@@ -677,28 +818,57 @@ class Server:
         return CacheInfo(hits=self._hits, misses=self._misses,
                          entries=len(self._runners), jit_compiles=compiles)
 
-    def report(self) -> ServeReport:
+    def report(self, since: Optional[float] = None) -> ServeReport:
+        """Service metrics (:class:`ServeReport`) over the requests
+        submitted and the launches started at or after ``since`` (a time
+        on the server's clock; everything by default)."""
+        lo = -np.inf if since is None else since
+        reqs = [r for r in self._req_rows if r.submit >= lo]
+        launches = [b for b in self._batch_rows if b.start >= lo]
         per_bucket: dict = {}
-        for key in {row[0] for row in self._batch_rows}:
-            rows = [r for r in self._req_rows if r[0] == key]
-            launches = [row for row in self._batch_rows if row[0] == key]
+        for key in {b.bucket for b in launches}:
+            rows = [r for r in reqs if r.bucket == key]
+            mine = [b for b in launches if b.bucket == key]
             per_bucket[key] = BucketStats(
-                batches=len(launches),
+                batches=len(mine),
                 requests=len(rows),
-                pad_lanes=sum(row[2] for row in launches),
-                occupancy=float(np.mean([row[3] for row in launches])),
-                mean_wait=float(np.mean([r[2] for r in rows])),
-                mean_ttr=float(np.mean([r[3] for r in rows])))
+                pad_lanes=sum(b.pad for b in mine),
+                occupancy=float(np.mean([b.occupancy for b in mine])),
+                mean_wait=_mean([r.fill - r.submit for r in rows]))
         per_tenant: dict = {}
-        for tenant in {r[1] for r in self._req_rows}:
-            rows = [r for r in self._req_rows if r[1] == tenant]
+        for tenant in {r.tenant for r in reqs}:
+            rows = [r for r in reqs if r.tenant == tenant]
             per_tenant[tenant] = TenantStats(
                 requests=len(rows),
-                mean_wait=float(np.mean([r[2] for r in rows])),
-                mean_ttr=float(np.mean([r[3] for r in rows])))
-        return ServeReport(requests=len(self._req_rows),
-                           batches=len(self._batch_rows),
-                           per_bucket=per_bucket, per_tenant=per_tenant)
+                mean_wait=_mean([r.fill - r.submit for r in rows]))
+        hold = [r.submit - r.enter for r in reqs]
+        wait = [r.fill - r.submit for r in reqs]
+        in_lane = [r.done - r.fill for r in reqs]
+        slices = [r.slices for r in reqs]
+        delay = [b.start - b.oldest for b in launches]
+        wall = [b.end - b.start for b in launches]
+        queue = [b.queue for b in launches]
+        runs = [b.slices for b in launches]
+        phase_s = {p: sum(b.phase_s[p] for b in launches)
+                   for p in LAUNCH_PHASES}
+        return ServeReport(
+            requests=len(reqs), batches=len(launches),
+            per_bucket=per_bucket, per_tenant=per_tenant,
+            submit_hold_mean=_mean(hold), submit_hold_max=_max(hold),
+            queue_wait_p50=_pct(wait, 50), queue_wait_p95=_pct(wait, 95),
+            in_lane_mean=_mean(in_lane), in_lane_p50=_pct(in_lane, 50),
+            in_lane_p95=_pct(in_lane, 95),
+            slices_p50=_pct(slices, 50), slices_p95=_pct(slices, 95),
+            launch_delay_mean=_mean(delay), launch_delay_max=_max(delay),
+            launch_wall_mean=_mean(wall), launch_wall_max=_max(wall),
+            launch_queue_mean=_mean(queue), launch_queue_max=_max(queue),
+            launch_slices_mean=_mean(runs), launch_slices_max=_max(runs),
+            host_share=(1.0 - phase_s["slice"] / sum(wall) if sum(wall)
+                        else float("nan")),
+            phase_s=phase_s,
+            launches_by_reason={
+                why: sum(b.reason == why for b in launches)
+                for why in LAUNCH_REASONS})
 
 
 def serve(spec: Optional[ServeSpec] = None, *, clock=None,
@@ -721,4 +891,5 @@ def serve(spec: Optional[ServeSpec] = None, *, clock=None,
 
 __all__ = ["serve", "Server", "ServeSpec", "ServeReport", "BucketStats",
            "TenantStats", "CacheInfo", "QueueFullError", "SystemClock",
-           "ManualClock", "AUTO_SLICE_STEPS"]
+           "ManualClock", "AUTO_SLICE_STEPS", "LAUNCH_REASONS",
+           "LAUNCH_PHASES"]
