@@ -312,6 +312,7 @@ def make_machine(spec: MachineSpec, max_prog: int = 256,
             mr_active=jnp.bool_(False), mr_rem=I32(0),
             halted=jnp.bool_(False), overflow=jnp.bool_(False),
             stall_cycles=I32(0), spec_aborted=I32(0), steps=I32(0),
+            mem_writes=I32(0),
             # uid-indexed trace
             tr_func=jnp.full((U,), NEG, I32), tr_dispatch=jnp.full((U,), NEG, I32),
             tr_issue=jnp.full((U,), NEG, I32), tr_complete=jnp.full((U,), NEG, I32),
@@ -350,11 +351,18 @@ def make_machine(spec: MachineSpec, max_prog: int = 256,
     # iteration, and a per-unit loop of (total_mem,)-wide copies in the
     # hot body is what made population batches slower than a Python loop.
     # Memory effect-writes go through `copy_window` (a max_out_words-wide
-    # dynamic-update-slice, sequential per unit — exact last-writer
-    # ordering); the CDB enqueue is vectorised with rank computations:
-    # the k-th completing unit (by FU index) takes the k-th free slot (by
-    # slot index) and the k-th consecutive ticket, which is precisely what
-    # the sequential argmin loop produced.
+    # dynamic-update-slice).  Under the vmap its per-lane slice and update
+    # lower to a serial loop over lanes, so it runs once per window that is
+    # actually written: a `while_loop` over the units that complete this
+    # trip, in FU-index order (exact last-writer ordering), whose k-th
+    # iteration picks the k-th completing unit from the completion ranks by
+    # a masked reduction over the units (a per-lane gather would lower to
+    # the same lane loop).  Batched, it runs the most completions of any
+    # lane — usually none.  The CDB enqueue is vectorised with the same
+    # ranks: the k-th completing unit (by FU index) takes the k-th free
+    # slot (by slot index) and the k-th consecutive ticket, which is
+    # precisely what the sequential argmin loop produced.  ``xla_base``
+    # keeps the loop over every unit.
     # ------------------------------------------------------------------
     W = spec.max_out_words
     w_iota = jnp.arange(W, dtype=I32)
@@ -375,9 +383,18 @@ def make_machine(spec: MachineSpec, max_prog: int = 256,
                                             jnp.where(mask, vals, cur),
                                             (dst_c,))
 
+    def copy_each(mem, count, window):
+        """``mem = window(k, mem)`` for k in ``range(count)``: a while loop,
+        so batched it runs the largest ``count`` of any lane, and no window
+        is copied on a trip where no lane writes."""
+        return jax.lax.while_loop(
+            lambda c: c[0] < count,
+            lambda c: (c[0] + 1, window(c[0], c[1])), (I32(0), mem))[1]
+
     @_named("fu_exec")
     def fu_exec(st, exists, effect, alive):
-        """Per-unit execution tick + completion memory writes; returns the
+        """Per-unit execution tick + completion memory writes (one window
+        copy per completing unit, counted in ``mem_writes``); returns the
         ``done`` mask for the slot-side CDB enqueue (its own phase so the
         pallas machine can vmap this half and kernel the enqueue)."""
         busy = st["fu_busy"] & exists & alive
@@ -387,12 +404,28 @@ def make_machine(spec: MachineSpec, max_prog: int = 256,
         st["fu_rem"] = rem
 
         # --- memory writes (FU-index order: later units overwrite)
-        def mem_trip(i, mem):
-            return copy_window(mem, effect, st["fu_out_s"][i],
-                               st["fu_src"][i],
-                               st["fu_out_e"][i] - st["fu_out_s"][i],
-                               done[i])
-        st["mem"] = jax.lax.fori_loop(0, NFU, mem_trip, st["mem"])
+        n_done = jnp.sum(done, dtype=I32)
+        st["mem_writes"] = st["mem_writes"] + n_done
+        if base:
+            def mem_trip(i, mem):
+                return copy_window(mem, effect, st["fu_out_s"][i],
+                                   st["fu_src"][i],
+                                   st["fu_out_e"][i] - st["fu_out_s"][i],
+                                   done[i])
+            st["mem"] = jax.lax.fori_loop(0, NFU, mem_trip, st["mem"])
+            return st, done
+
+        csum = jnp.cumsum(done.astype(I32))
+
+        def write_kth(k, mem):
+            hit = done & (csum == k + 1)       # the k-th completing unit
+
+            def pick(a):
+                return jnp.sum(jnp.where(hit, a, 0), dtype=I32)
+            s, e = pick(st["fu_out_s"]), pick(st["fu_out_e"])
+            return copy_window(mem, effect, s, pick(st["fu_src"]), e - s,
+                               True)
+        st["mem"] = copy_each(st["mem"], n_done, write_kth)
         return st, done
 
     @_named("cdb_enqueue")
@@ -835,10 +868,16 @@ def make_machine(spec: MachineSpec, max_prog: int = 256,
         # drain path: TM full and a committed victim exists
         do_drain = is_task & spec & ~tm_avail
         vic_base = p.tm_base + st["tlb_slot"][victim] * p.tm_slot_words
-        st["mem"] = copy_window(st["mem"], st["mem"], st["tlb_os"][victim],
-                                vic_base,
-                                st["tlb_oe"][victim] - st["tlb_os"][victim],
-                                do_drain)
+        vic_s, vic_e = st["tlb_os"][victim], st["tlb_oe"][victim]
+        st["mem_writes"] = st["mem_writes"] + do_drain.astype(I32)
+        if base:
+            st["mem"] = copy_window(st["mem"], st["mem"], vic_s, vic_base,
+                                    vic_e - vic_s, do_drain)
+        else:
+            st["mem"] = copy_each(
+                st["mem"], do_drain.astype(I32),
+                lambda k, mem: copy_window(mem, mem, vic_s, vic_base,
+                                           vic_e - vic_s, True))
         st["tlb_valid"] = st["tlb_valid"] & ~(do_drain
                                               & (l_iota == victim))
         st["fe_wait"] = jnp.where(gmask & do_drain, p.tlb_drain_cycles,
@@ -1235,6 +1274,7 @@ def make_machine(spec: MachineSpec, max_prog: int = 256,
             cycles=st["cycle"], halted=st["halted"], overflow=st["overflow"],
             n_tasks=st["next_uid"] - 1, spec_aborted=st["spec_aborted"],
             stall_cycles=st["stall_cycles"], steps=st["steps"],
+            mem_writes=st["mem_writes"],
             fe_stall=st["fe_stall"],
             fu_busy_cycles=st["fu_busy_cycles"],
             mem=st["mem"], regs=st["regs"],
