@@ -61,13 +61,16 @@ def per_class(n_fu, cfg: dict) -> tuple:
             if np.ndim(n_fu) == 0 else tuple(int(k) for k in n_fu))
 
 
-def expect(built, cfg: dict, scheduler: str, n_fu, *, sched=None):
-    """The reference's outcome for one built program (``sched`` replaces
-    the configuration's cost model of ``scheduler``)."""
+def expect(built, cfg: dict, scheduler: str, n_fu, *, sched=None,
+           policy=None):
+    """The reference's outcome for one built program under ``policy``
+    (plain data, ``None`` for age order; ``sched`` replaces the
+    configuration's cost model of ``scheduler``)."""
     return reference.simulate(
         built.code, built.mem_init, built.effects, hw=cfg["hts"],
         sched=sched or cfg["schedulers"][scheduler],
-        func_cycles=func_cycles(cfg), n_fu=per_class(n_fu, cfg))
+        func_cycles=func_cycles(cfg), n_fu=per_class(n_fu, cfg),
+        policy=policy)
 
 
 def differs(got_cycles, got_halted, got_rows, want) -> bool:

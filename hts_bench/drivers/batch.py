@@ -6,6 +6,8 @@ the generated populations; ``sweep`` repeats the one FU sweep over the
 paper's programs.  A window's rate is every scenario of every call over
 the time from the first call's start to the last call's end: the window
 runs whole calls, and the last starts before ``seconds`` have passed.
+Where the traffic gives each program a scheduling policy, a ``run_many``
+call passes its lanes' policies, and the check gives each lane its own.
 """
 from __future__ import annotations
 
@@ -35,19 +37,24 @@ class Driver:
         progs = self.traffic.programs
         self.max_prog = max(self.t.get("max_prog", 16),
                             *(hts.prog_bucket(len(p.code)) for p in progs))
+        pols = self.traffic.policies
+        self.policies = None if pols is None else [
+            hts.SchedPolicy.of(**(d or {})) for d in pols]
         # warm-up: the one shape every call has (lanes, program table),
         # filled with an empty program, which halts on the first trip
         empty = hts.Program("warm_up").build()
         self._call([empty] * len(self.traffic.calls[0]))
 
-    def _call(self, progs):
+    def _call(self, progs, policies=None):
         from repro.core import hts
+        kw = {} if policies is None else {"policy": policies}
         with self.spans("bench.call"):
             if self.entry == "run_many":
                 return hts.run_many(progs, scheduler=self.cfg["scheduler"],
                                     n_fu=self.cfg["n_fu"],
                                     params=self.params,
-                                    max_prog=self.max_prog, check=False)
+                                    max_prog=self.max_prog, check=False,
+                                    **kw)
             return hts.sweep(progs, n_fu=self.t["n_fu"],
                              schedulers=self.t["schedulers"],
                              params=self.params, max_prog=self.max_prog,
@@ -59,9 +66,12 @@ class Driver:
         t0 = time.perf_counter()
         while not self.calls or time.perf_counter() - t0 < seconds:
             i = len(self.calls) % n_calls
-            progs = [self.traffic.programs[k] for k in self.traffic.calls[i]]
+            idx = self.traffic.calls[i]
+            progs = [self.traffic.programs[k] for k in idx]
+            pols = (None if self.policies is None
+                    else [self.policies[k] for k in idx])
             t_call = time.perf_counter()
-            self.calls.append((i, self._call(progs)))
+            self.calls.append((i, self._call(progs, pols)))
             self.call_s += time.perf_counter() - t_call
         wall = time.perf_counter() - t0
         lanes = sum(self._lanes(res) for _, res in self.calls)
@@ -137,9 +147,12 @@ class Driver:
             c, lane = flat[j]
             i, res = self.calls[c]
             got = res[lane]
-            want = common.expect(
-                self.traffic.programs[self.traffic.calls[i][lane]],
-                self.cfg, self.cfg["scheduler"], self.cfg["n_fu"])
+            k = self.traffic.calls[i][lane]
+            pol = (None if self.traffic.policies is None
+                   else self.traffic.policies[k])
+            want = common.expect(self.traffic.programs[k], self.cfg,
+                                 self.cfg["scheduler"], self.cfg["n_fu"],
+                                 policy=pol)
             bad += common.differs(got.cycles, got.halted,
                                   got.schedule_tuple(), want)
         return [

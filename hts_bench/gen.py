@@ -13,9 +13,11 @@ not move the yardstick.
 
 The scenario and paper-program builders below are copies of
 ``workloads.generate_scenario`` (with its default options: no policy, one
-merged frontend, uniform units) and of the paper programs of
-``programs.all_benches()``; ``hts_bench/tests/test_gen.py`` pins the
-copies to the originals on a few seeds.
+merged frontend, uniform units), of the paper programs of
+``programs.all_benches()`` and of the QoS policy grid of
+``benchmarks/population.build_grid``; ``hts_bench/tests/test_gen.py``
+pins the copies to the originals.  A scheduling policy travels as plain
+data (``reference.py``'s form), one per program, in ``Traffic.policies``.
 """
 from __future__ import annotations
 
@@ -299,6 +301,68 @@ PAPER_PROGRAMS = {
 
 
 # ---------------------------------------------------------------------------
+# the QoS policy grid: a latency-sensitive chain behind greedy floods, under
+# a grid of scheduling policies (the paper's N-CPU sharing, Sec. III)
+# ---------------------------------------------------------------------------
+HI_PID = 1                      # the latency-sensitive tenant
+
+
+def _hi_chain(chain: int = 8, delay: int = 10):
+    """Latency-sensitive tenant: a RAW chain arriving after ``delay``
+    nops."""
+    p = _program("hi", region_base=0x100)
+    frame = p.input(INPUT, INPUT_WORDS, "frame")
+    for _ in range(delay):
+        p.nop()
+    with p.process(HI_PID):
+        prev = frame
+        for i in range(chain):
+            prev = p.task("dct", in_=prev, out=4, in_size=4, tid=i)
+    return p
+
+
+def _greedy(pid: int, tasks: int = 10):
+    """Best-effort flood: independent same-class tasks."""
+    p = _program(f"greedy{pid}", region_base=0x180 + 0x80 * (pid - 2))
+    frame = p.input(INPUT, INPUT_WORDS, "frame")
+    with p.process(pid):
+        for i in range(tasks):
+            p.task("dct", in_=frame, out=4, tid=i & 0xF)
+    return p
+
+
+def _contended(n_greedy: int):
+    from repro.core import hts
+    return hts.Program.merge(
+        [_hi_chain()] + [_greedy(2 + k) for k in range(n_greedy)],
+        f"contended_{n_greedy}g", require_distinct_pids=True)
+
+
+def qos_grid(mixes=(2, 3, 4, 5), weights=(0, 1, 2, 8), quotas=(None, 1),
+             rs_caps=(None, 4)) -> list:
+    """(built program, policy) of every instance of the tenant-mix x policy
+    grid, in ``build_grid``'s order: per mix of greedy floods, pid 1's
+    priority weight, the floods' FU quota and their RS cap (``None`` or 0:
+    not set).  A policy is plain data and ``{}`` where nothing is set."""
+    out = []
+    for g in mixes:
+        built = _contended(g).build()
+        floods = range(2, 2 + g)
+        for w in weights:
+            for q in quotas:
+                for rc in rs_caps:
+                    pol = {}
+                    if w:
+                        pol["weights"] = {HI_PID: w}
+                    if q:
+                        pol["quotas"] = {p: q for p in floods}
+                    if rc:
+                        pol["rs_caps"] = {p: rc for p in floods}
+                    out.append((built, pol))
+    return out
+
+
+# ---------------------------------------------------------------------------
 # what a generator kind returns, and the lookup by name
 # ---------------------------------------------------------------------------
 @dataclasses.dataclass
@@ -309,6 +373,9 @@ class Traffic:
     #: per call: indices into ``programs`` (batch drivers)
     arrivals: np.ndarray = None      # due times in seconds (serve driver)
     requests: np.ndarray = None      # per arrival: index into ``programs``
+    #: per program: its scheduling policy as plain data, or ``None`` for
+    #: age order (batch drivers); ``None`` here: no program has one
+    policies: list = None
 
 
 def kind(package: str, name: str):

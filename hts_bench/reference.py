@@ -7,8 +7,29 @@ it decodes the 128-bit Table-I instruction words itself and steps every
 cycle, with no event skipping, no batching and no compiled code.
 
 Scope: the deployments the benchmark runs.  One merged dispatch stream,
-the paper's age-order RS arbiter, identical units within a class.  A
-scenario outside that scope is refused, not approximated.
+the RS arbiter with per-pid priority weights, FU quotas and RS admission
+caps (``policy``, below; with none it is the paper's age order),
+identical units within a class.  A scenario or policy outside that scope
+is refused, not approximated.
+
+A policy is plain data, ``{"weights": {pid: w}, "quotas": {pid: q},
+"rs_caps": {pid: c}}``, each key optional (pids may be strings, as JSON
+writes them).  Its rules, as the program's ``SchedPolicy`` states them:
+
+* RS issue takes ready entries in order of the key
+  ``(4096 - clip(weight, 0, 4096)) * 2**17 + age``: the higher weight
+  first, age order within a weight.  A pid that is not listed weighs 0.
+* A pid that already holds ``quota`` busy units of an entry's class (units
+  issued earlier in the same cycle count) has that entry skipped without
+  using up the unit, so the arbiter stays work-conserving.  The issue
+  width and the lowest-free-unit rule are unchanged.
+* At dispatch, a TASK whose pid holds ``rs_cap`` RS entries stalls the
+  frontend exactly as a full RS does.
+
+``"fe_mode"`` may be given only as ``"rr"`` and ``"issue_mode"`` only as
+``"greedy"``: per-tenant frontends and per-unit costs are outside the
+scope.  No policy, or one that lists no quota, no cap and no weight other
+than 0, gives the age-order answers.
 
 Within one cycle the phases run in this order: FU tick, memory-read tick,
 CDB grant (ticket order), branch resolve (commit or squash), RS issue,
@@ -16,6 +37,7 @@ frontend dispatch, halt check.
 """
 from __future__ import annotations
 
+import collections
 import dataclasses
 
 # Table I opcodes: accelerator ids at or above 0xF0 are control instructions
@@ -26,6 +48,8 @@ _CTRL = {0xF0: OP_NOP, 0xF1: OP_ADD, 0xF2: OP_MUL, 0xF3: OP_MOV,
 BR_RR, BR_MR, BR_BR = 0, 1, 2                   # register, memory, bus read
 CND_EQ, CND_NEQ, CND_GE = 0, 1, 2               # 3 = LE
 CTL_IN_INDIRECT, CTL_OUT_INDIRECT, CTL_IMM = 1, 2, 1
+PRIO_CAP, AGE_SPAN = 4096, 1 << 17             # the RS issue key's terms
+POLICY_KEYS = {"weights", "quotas", "rs_caps", "fe_mode", "issue_mode"}
 
 
 @dataclasses.dataclass(frozen=True)
@@ -74,7 +98,8 @@ class _Entry:                   # one reservation-station entry
     uid: int
     func: int
     dep: int
-    age: int
+    key: int                    # issue order: weight, then age
+    pid: int
     out_s: int
     out_e: int
     src_s: int
@@ -89,14 +114,45 @@ class Outcome:
     #                             broadcast, aborted, pid) per task
 
 
+def _policy(policy) -> tuple:
+    """(weights, quotas, rs_caps) of a plain-data policy, each a dict of
+    int pid to int value; refuses what the reference does not model."""
+    policy = policy or {}
+    unknown = set(policy) - POLICY_KEYS
+    if unknown:
+        raise ValueError(f"reference: policy keys {sorted(unknown)} are not "
+                         f"modelled (known: {sorted(POLICY_KEYS)})")
+    if policy.get("fe_mode", "rr") != "rr":
+        raise ValueError(f"reference: fe_mode {policy['fe_mode']!r}: only "
+                         "'rr' is modelled, since the reference runs one "
+                         "merged dispatch stream")
+    if policy.get("issue_mode", "greedy") != "greedy":
+        raise ValueError(f"reference: issue_mode {policy['issue_mode']!r}: "
+                         "only 'greedy' is modelled, since the reference's "
+                         "units within a class are identical")
+
+    weights, quotas, rs_caps = (
+        {int(p): int(v) for p, v in (policy.get(key) or {}).items()}
+        for key in ("weights", "quotas", "rs_caps"))
+    for key, caps in (("quotas", quotas), ("rs_caps", rs_caps)):
+        if any(v < 1 for v in caps.values()):
+            raise ValueError(f"reference: {key} {caps}: a cap under 1 lets "
+                             "the pid run nothing")
+    return ({p: min(max(w, 0), PRIO_CAP) for p, w in weights.items()},
+            quotas, rs_caps)
+
+
 def simulate(code, mem_init: dict, effects: dict, *, hw: dict, sched: dict,
-             func_cycles: list, n_fu, max_cycles: int = 5_000_000) -> Outcome:
+             func_cycles: list, n_fu, policy=None,
+             max_cycles: int = 5_000_000) -> Outcome:
     """Run one scenario; return its cycle count and per-task schedule rows.
 
     ``hw`` holds the HTS structure sizes, ``sched`` one scheduler cost
     model and ``func_cycles`` the Table-II latency of each function class,
-    all as a configuration file states them.
+    all as a configuration file states them; ``policy`` is the scheduling
+    policy as plain data (module docstring), ``None`` for age order.
     """
+    weights, quotas, rs_caps = _policy(policy)
     prog = decode(code)
     end = len(prog)
     n_fu = [int(k) for k in n_fu]
@@ -164,8 +220,9 @@ def simulate(code, mem_init: dict, effects: dict, *, hw: dict, sched: dict,
         tracker[:] = [t for t in tracker
                       if not (t[0] < out_e and out_s < t[1])]
         tracker.append([out_s, out_e, next_uid, spec])
-        rs.append(_Entry(next_uid, ins.acc, dep, age, out_s, out_e, src_s,
-                         spec))
+        key = (PRIO_CAP - weights.get(ins.pid, 0)) * AGE_SPAN + age
+        rs.append(_Entry(next_uid, ins.acc, dep, key, ins.pid, out_s, out_e,
+                         src_s, spec))
         t = _Task(next_uid, ins.acc, cycle, ins.pid)
         tasks.append(t)
         by_uid[next_uid] = t
@@ -243,9 +300,15 @@ def simulate(code, mem_init: dict, effects: dict, *, hw: dict, sched: dict,
                 spec_on = False
                 regs_ckpt = None
             br = None
-        # 5. RS issue: ready entries oldest first, lowest free unit
+        # 5. RS issue: ready entries by weight, then oldest first, lowest
+        # free unit; an entry whose pid holds its quota of busy units of
+        # the class is skipped
         issued = 0
-        for r in sorted(rs, key=lambda r: r.age):
+        if quotas:                      # (pid, class) -> busy units
+            busy = collections.Counter(
+                (by_uid[t[0]].pid, fu_cls[u])
+                for u, t in enumerate(fu_task) if t is not None)
+        for r in sorted(rs, key=lambda r: r.key):
             if issued >= issue_width:
                 break
             if r.dep:
@@ -254,6 +317,10 @@ def simulate(code, mem_init: dict, effects: dict, *, hw: dict, sched: dict,
                     if fu_cls[u] == r.func and fu_task[u] is None]
             if not free:
                 continue
+            if quotas:
+                if busy[r.pid, r.func] >= quotas.get(r.pid, n_units):
+                    continue
+                busy[r.pid, r.func] += 1
             fu_task[free[0]] = (r.uid, r.out_s, r.out_e, r.src_s, r.spec)
             fu_rem[free[0]] = func_cycles[r.func]
             by_uid[r.uid].issue = cycle
@@ -266,7 +333,9 @@ def simulate(code, mem_init: dict, effects: dict, *, hw: dict, sched: dict,
             if in_order and not empty():
                 ok = False
             elif len(rs) >= hw["rs_entries"] or \
-                    len(tracker) >= hw["tracker_entries"]:
+                    len(tracker) >= hw["tracker_entries"] or \
+                    ins.pid in rs_caps and \
+                    sum(r.pid == ins.pid for r in rs) >= rs_caps[ins.pid]:
                 ok = False
             elif spec_on:
                 if not tm_free:

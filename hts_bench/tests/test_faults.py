@@ -143,3 +143,73 @@ def test_control_fails_the_comparison(small, cell):
     readings = [control.reading(spec, seed, seconds=1.0)
                 for seed in (1, 2, 3)]
     assert all(r["differing"] > 0 for r in readings), readings
+
+
+# -- per-lane scheduling policies ------------------------------------------
+def _grid_traffic(seed, t=None, seconds=None):
+    """The QoS policy grid at a size the CPU holds: the 8 instances of its
+    2-flood mix under weights 0 and 8, in an order set by the seed."""
+    from hts_bench import gen
+    pairs = gen.qos_grid(mixes=(2,), weights=(0, 8))
+    order = np.random.default_rng([seed, 0]).permutation(len(pairs))
+    return gen.Traffic([pairs[k][0] for k in order],
+                       [list(range(len(pairs)))],
+                       policies=[pairs[k][1] for k in order])
+
+
+def _grid_spec():
+    """The grid's cell as the next configuration would state it: the
+    generated mix's machine, with the policy control."""
+    cfg = json.loads((run.BENCH / "configs" / "generated_mix.json")
+                     .read_text())
+    return {"config": dict(cfg, control="policy"),
+            "traffic": {"driver": "batch", "entry": "run_many",
+                        "capacities": {"max_tasks": 192, "cdb_entries": 64},
+                        "max_prog": 64, "check_lanes": 8}}
+
+
+@pytest.mark.parametrize("mode", ["policies", "none", "dropped"])
+def test_policies_reach_run_many(monkeypatch, mode):
+    """The batch driver passes each lane's policy to ``run_many`` (and no
+    ``policy=`` at all for traffic without policies), and its check holds
+    each lane to its own policy: a program that drops them is caught."""
+    from hts_bench import common, gen
+    from hts_bench.drivers import batch
+    from repro.core import hts
+    traffic = _grid_traffic(5)
+    if mode == "none":
+        traffic.policies = None
+    monkeypatch.setattr(gen, "generate", lambda *a: traffic)
+    seen = []
+    real = hts.run_many
+
+    def recording(*a, **k):
+        seen.append(k.get("policy", "absent"))
+        if mode == "dropped":
+            k.pop("policy", None)
+        return real(*a, **k)
+
+    monkeypatch.setattr(hts, "run_many", recording)
+    spec = _grid_spec()
+    drv = batch.Driver(spec["config"], spec["traffic"], 5, 0.0,
+                       common.Spans())
+    drv.setup()
+    drv.window(0.0)
+    checks = {c.name: c.value for c in drv.check()}
+    assert seen[0] == "absent"                  # the warm-up
+    if mode == "none":
+        assert seen[1:] == ["absent"]
+    else:
+        assert seen[1:] == [[hts.SchedPolicy.of(**d)
+                             for d in traffic.policies]]
+    bad = checks["lanes_differing_from_reference"]
+    assert (bad > 0) == (mode == "dropped"), checks
+
+
+def test_policy_control_fails_the_comparison(monkeypatch):
+    from hts_bench import gen
+    monkeypatch.setattr(gen, "generate", _grid_traffic)
+    spec = _grid_spec()
+    readings = [control.reading(spec, seed, seconds=1.0)
+                for seed in (1, 2, 3)]
+    assert all(r["differing"] > 0 for r in readings), readings

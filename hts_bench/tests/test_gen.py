@@ -40,6 +40,17 @@ def test_random_dependency_matches(seed):
           programs.random_dependency(seed=seed).program.build())
 
 
+def test_qos_grid_matches_build_grid():
+    from benchmarks import population
+    from repro.core import hts
+    want = population.build_grid()
+    got = gen.qos_grid()
+    assert len(got) == len(want) == 64
+    for (built, policy), (built_0, policy_0) in zip(got, want):
+        _same(built, built_0)
+        assert hts.SchedPolicy.of(**policy) == policy_0
+
+
 def test_traffic_depends_on_the_seed_alone():
     t = {"generator": "pool", "pool": 4, "n_tenants": 2,
          "kernels": ["dct", "vector_dot"], "max_tasks": 3,
