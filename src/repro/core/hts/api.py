@@ -488,6 +488,9 @@ class PopulationResult:
     _spec: Any = dataclasses.field(repr=False, default=None)
     _max_prog: Optional[int] = dataclasses.field(repr=False, default=None)
     _margs: Any = dataclasses.field(repr=False, default=None)
+    #: host time ``run_many`` spent packing the programs (the ``hts.pack``
+    #: span), not part of ``wall_us``; 0.0 when handed a PackedPopulation
+    pack_us: float = 0.0
 
     def __len__(self) -> int:
         return len(self.names)
@@ -652,7 +655,8 @@ def run_many(programs, *,
             halted=np.asarray([r.halted for r in results]), wall_us=wall,
             max_fu_per_class=pop.widest_fu, policies=pop.policies,
             _results=results,
-            stream_sets=tuple(p.streams for p in pop.preps))
+            stream_sets=tuple(p.streams for p in pop.preps),
+            pack_us=spans.seconds("hts.pack") * 1e6)
     if backend != "jax":
         raise ValueError(f'backend must be "jax" or "golden", got {backend!r}')
 
@@ -689,7 +693,8 @@ def run_many(programs, *,
         max_fu_per_class=max_fu_per_class, policies=pop.policies, raw=out,
         stream_sets=tuple(p.streams for p in pop.preps),
         _spec=spec, _max_prog=pop.max_prog,
-        _margs=tuple(run_pop.machine_args()))
+        _margs=tuple(run_pop.machine_args()),
+        pack_us=spans.seconds("hts.pack") * 1e6)
     if check and not result.all_halted:
         bad = [pop.names[i] for i in np.nonzero(~halted)[0]]
         raise SimulationError(

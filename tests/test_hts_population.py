@@ -7,6 +7,8 @@ individual runs.  The slow tier drives a whole generated population
 through ONE vmapped machine batch and requires bit-identical schedules
 against a per-scenario golden loop.
 """
+import time
+
 import numpy as np
 import pytest
 
@@ -190,6 +192,28 @@ def test_run_many_per_scenario_policies(small_pop):
         solo = hts.run(prog, n_fu=2, policy=pols[i], max_prog=MAX_PROG,
                        max_fu_per_class=pr.max_fu_per_class)
         assert solo.schedule == pr[i].schedule, i
+
+
+@pytest.mark.parametrize("backend", ["jax", "golden"])
+def test_run_many_pack_us_times_the_pack(small_pop, backend):
+    """``pack_us`` is the ``hts.pack`` span: positive when ``run_many``
+    packs, inside the call's own wall, and apart from the launch's
+    ``wall_us``."""
+    t0 = time.perf_counter()
+    pr = hts.run_many(small_pop, n_fu=2, max_prog=MAX_PROG, backend=backend)
+    call_us = (time.perf_counter() - t0) * 1e6
+    assert 0.0 < pr.pack_us <= call_us
+    assert pr.pack_us + pr.wall_us <= call_us
+
+
+@pytest.mark.parametrize("backend", ["jax", "golden"])
+def test_run_many_prepacked_has_no_pack_time(small_pop, backend):
+    pop = batch.pack_population(small_pop, n_fu=2, max_prog=MAX_PROG)
+    pr = hts.run_many(pop, backend=backend)
+    assert pr.pack_us == 0.0 and pr.wall_us > 0.0
+    assert [int(c) for c in pr.cycles] == [
+        int(c) for c in hts.run_many(small_pop, n_fu=2, max_prog=MAX_PROG,
+                                     backend=backend).cycles]
 
 
 def test_sweep_population_mode(small_pop):

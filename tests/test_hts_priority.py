@@ -277,6 +277,26 @@ def test_fairness_report_carries_weights():
     assert plain.merged.asm == sc.merged.asm and plain.policy is None
 
 
+def test_qos_grid_slice_run_many_matches_golden():
+    """The QoS grid's 5-flood mix under all 16 of its policies (pid 1's
+    weight x the floods' FU quota x their RS cap), one lane each in one
+    ``run_many`` call, agrees row for row with the golden loop."""
+    from benchmarks import population
+    grid = population.build_grid(mixes=(5,))
+    assert len(grid) == 16
+    progs, pols = zip(*grid)
+    params = hts.HtsParams(max_tasks=192, cdb_entries=64)
+    kw = dict(n_fu=2, params=params, policy=list(pols))
+    got = hts.run_many(list(progs), max_prog=128, **kw)
+    want = hts.run_many(list(progs), backend="golden", **kw)
+    assert got.all_halted and want.all_halted
+    for i in range(len(grid)):
+        assert got[i].cycles == want[i].cycles, i
+        assert got[i].schedule == want[i].schedule, i
+    # the policies bind: the lanes do not all schedule alike
+    assert len({r.schedule for r in want}) > 1
+
+
 # ---------------------------------------------------------------------------
 # the mixed-priority differential fuzzer (fast tier: >= 25 seeds)
 # ---------------------------------------------------------------------------
